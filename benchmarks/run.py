@@ -1148,38 +1148,6 @@ def bench_tp():
          f"hydra_tp_zero3_cut_pct={metrics['hydra_tp_zero3_cut_pct']}")
 
 
-def bench_zero_tpu():
-    """Beyond-paper: the R2 strategy comparison on the real TPU mesh
-    (subprocess — needs 512 forced host devices before jax init)."""
-    import subprocess
-    import sys
-    t0 = time.time()
-    root = os.path.join(os.path.dirname(__file__), "..")
-    path = os.path.join(root, "zero_tpu_study.txt")
-    if os.path.exists(path):
-        print("\n== R2 on the TPU runtime (cached zero_tpu_study.txt) ==")
-        print(open(path).read())
-    else:
-        env = dict(os.environ,
-                   PYTHONPATH=os.path.join(root, "src"),
-                   XLA_FLAGS="--xla_force_host_platform_device_count=512")
-        code = (
-            "from repro.launch.roofline import analyze_one\n"
-            "from repro.launch.mesh import make_production_mesh\n"
-            "from repro.sharding import ShardingStrategy\n"
-            "mesh = make_production_mesh()\n"
-            "for z in (1, 2, 3):\n"
-            "    r = analyze_one('llama3_2_3b', 'train_4k', mesh,\n"
-            "                    strat=ShardingStrategy(zero_stage=z))\n"
-            "    print(z, r['device_mem_gib'], r['memory_s'],"
-            " r['collective_s'])\n")
-        r = subprocess.run([sys.executable, "-c", code], env=env,
-                           capture_output=True, text=True, timeout=1200)
-        print("\n== R2 on the TPU runtime ==")
-        print(r.stdout or r.stderr[-500:])
-    _csv("zero_tpu", (time.time() - t0) * 1e6)
-
-
 def bench_roofline():
     root = os.path.join(os.path.dirname(__file__), "..")
     path = os.path.join(root, "roofline_final.json")
@@ -1218,7 +1186,6 @@ BENCHES = {
     "tp": bench_tp,
     "kernels": bench_kernels,
     "grpo": bench_grpo,
-    "zero_tpu": bench_zero_tpu,
     "roofline": bench_roofline,
 }
 
@@ -1244,6 +1211,8 @@ def main() -> None:
                          "to HISTORY_<name>.jsonl here (render with "
                          "launch/report.py --trend); '' disables")
     args = ap.parse_args()
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     _EMIT_TRACE[0] = args.emit_trace
     print("name,us_per_call,derived")
     try:

@@ -77,11 +77,12 @@ def restore(ckpt_dir: str, step: int, like: Any, shardings: Any = None,
             if not kind_ok:         # no such space: stay host-resident
                 leaves.append(arr)
                 continue
-            if sh is not None:
-                sh = sh.with_memory_kind(memory_kind)
-            else:
-                sh = jax.sharding.SingleDeviceSharding(
-                    jax.devices()[0], memory_kind=memory_kind)
+            if sh is None:
+                # no target sharding given: keep the one ``like`` has;
+                # only a bare shape lands on the default device
+                sh = getattr(leaf, "sharding", None) or \
+                    jax.sharding.SingleDeviceSharding(jax.devices()[0])
+            sh = sh.with_memory_kind(memory_kind)
         leaves.append(jax.device_put(arr, sh) if sh is not None
                       else jax.device_put(arr))
     return jax.tree_util.tree_unflatten(treedef, leaves)

@@ -5,9 +5,10 @@ One program per (batch, kv-head); the cache-length dimension is the
 innermost sequential grid axis, with fp32 (acc, m, l) scratch carrying the
 online softmax across cache blocks. Masking is data-driven: the cache's
 per-slot absolute positions (``pos``, -1 = empty) are streamed alongside
-K/V, so rolling-buffer wraparound and sliding windows need no index
-arithmetic in the host code. All G query heads of a KV group are processed
-together ([G, D] x [D, block_c] on the MXU).
+K/V as ``[1, block_c]`` lane rows, so rolling-buffer wraparound and sliding
+windows need no index arithmetic in the host code. The query position is a
+scalar-prefetched SMEM operand. All G query heads of a KV group are
+processed together ([G, D] x [D, block_c] on the MXU).
 """
 from __future__ import annotations
 
@@ -19,12 +20,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 
 NEG_INF = -1e30
 
 
-def _decode_kernel(q_ref, k_ref, v_ref, pos_ref, cur_ref, o_ref,
+def _decode_kernel(cur_ref, q_ref, k_ref, v_ref, pos_ref, o_ref,
                    acc_ref, m_ref, l_ref, *, window: int, scale: float):
     j = pl.program_id(1)
     nc = pl.num_programs(1)
@@ -40,12 +40,12 @@ def _decode_kernel(q_ref, k_ref, v_ref, pos_ref, cur_ref, o_ref,
     v = v_ref[0].astype(jnp.float32)                    # [bc, Dv]
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # [G, bc]
 
-    pos = pos_ref[0]                                    # [bc] int32
-    cur = cur_ref[0, 0]
+    pos = pos_ref[0]                                    # [1, bc] int32
+    cur = cur_ref[pl.program_id(0)]
     valid = (pos >= 0) & (pos <= cur)
     if window:
         valid &= pos > (cur - window)
-    s = jnp.where(valid[None, :], s, NEG_INF)
+    s = jnp.where(valid, s, NEG_INF)
 
     m_prev = m_ref[...]
     m_cur = jnp.maximum(m_prev, s.max(axis=1))
@@ -80,31 +80,36 @@ def decode_attention(q, k_cache, v_cache, pos, position, *, window: int = 0,
     qh = q.reshape(B * K, G, D)
     kh = kp.transpose(0, 2, 1, 3).reshape(B * K, C + pc, D)
     vh = vp.transpose(0, 2, 1, 3).reshape(B * K, C + pc, Dv)
-    posh = jnp.repeat(posp, K, axis=0)                  # [B*K, C+pc]
-    curh = jnp.repeat(position.astype(jnp.int32)[:, None], K, axis=0)
+    # [B*K, 1, C+pc]: each block's last two dims are (1, block_c), the
+    # full unit dim and a lane-aligned (or full) cache block
+    posh = jnp.repeat(posp, K, axis=0)[:, None, :]
+    curh = jnp.repeat(position.astype(jnp.int32), K)    # [B*K] -> SMEM
     nc = (C + pc) // block_c
 
     kernel = functools.partial(_decode_kernel, window=window,
                                scale=1.0 / math.sqrt(D))
-    out = pl.pallas_call(
-        kernel,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,                   # per-row query position
         grid=(B * K, nc),
         in_specs=[
-            pl.BlockSpec((1, G, D), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, block_c, D), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, block_c, Dv), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, block_c), lambda b, j: (b, j)),
-            pl.BlockSpec((1, 1), lambda b, j: (b, 0)),
+            pl.BlockSpec((1, G, D), lambda b, j, cur: (b, 0, 0)),
+            pl.BlockSpec((1, block_c, D), lambda b, j, cur: (b, j, 0)),
+            pl.BlockSpec((1, block_c, Dv), lambda b, j, cur: (b, j, 0)),
+            pl.BlockSpec((1, 1, block_c), lambda b, j, cur: (b, 0, j)),
         ],
-        out_specs=pl.BlockSpec((1, G, Dv), lambda b, j: (b, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B * K, G, Dv), q.dtype),
+        out_specs=pl.BlockSpec((1, G, Dv), lambda b, j, cur: (b, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((G, Dv), jnp.float32),
             pltpu.VMEM((G,), jnp.float32),
             pltpu.VMEM((G,), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B * K, G, Dv), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(qh, kh, vh, posh, curh)
+    )(curh, qh, kh, vh, posh)
     return out.reshape(B, H, Dv)

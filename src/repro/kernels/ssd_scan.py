@@ -23,7 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 
 
 def _ssd_kernel(x_ref, a_ref, b_ref, c_ref, y_ref, fin_ref, state_ref, *,
@@ -36,16 +35,25 @@ def _ssd_kernel(x_ref, a_ref, b_ref, c_ref, y_ref, fin_ref, state_ref, *,
 
     P, N = state_ref.shape
     x = x_ref[...].reshape(chunk, P).astype(jnp.float32)
-    a = a_ref[...].reshape(chunk).astype(jnp.float32)
+    a = a_ref[...].reshape(1, chunk).astype(jnp.float32)
     b = b_ref[...].reshape(chunk, N).astype(jnp.float32)
     c = c_ref[...].reshape(chunk, N).astype(jnp.float32)
 
-    a_cum = jnp.cumsum(a)                        # [l]
-    # segsum: L[i,j] = exp(sum_{j<k<=i} a_k) for j<=i else 0
-    diff = a_cum[:, None] - a_cum[None, :]
+    # inclusive prefix sum of the log-decays as two triangular matmuls
+    # (Mosaic has no cumsum), once as a column and once as a row; HIGHEST
+    # keeps the f32 sums exact instead of rounding operands to bf16
     ii = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     jj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    L = jnp.where(jj <= ii, jnp.exp(diff), 0.0)  # [l, l]
+    hi = jax.lax.Precision.HIGHEST
+    a_cum_col = jax.lax.dot_general(
+        (jj <= ii).astype(jnp.float32), a, (((1,), (1,)), ((), ())),
+        precision=hi)                            # [l, 1]
+    a_cum_row = jax.lax.dot_general(
+        a, (ii <= jj).astype(jnp.float32), (((1,), (0,)), ((), ())),
+        precision=hi)                            # [1, l]
+    a_sum = jnp.sum(a)
+    # segsum: L[i,j] = exp(sum_{j<k<=i} a_k) for j<=i else 0
+    L = jnp.where(jj <= ii, jnp.exp(a_cum_col - a_cum_row), 0.0)  # [l, l]
 
     cb = jax.lax.dot_general(c, b, (((1,), (1,)), ((), ())))   # [l, l]
     y_diag = jax.lax.dot_general((cb * L).astype(x.dtype), x,
@@ -53,14 +61,14 @@ def _ssd_kernel(x_ref, a_ref, b_ref, c_ref, y_ref, fin_ref, state_ref, *,
 
     state = state_ref[...]                       # [P, N] fp32
     y_off = jax.lax.dot_general(
-        c * jnp.exp(a_cum)[:, None], state,
+        c * jnp.exp(a_cum_col), state,
         (((1,), (1,)), ((), ())))                # [l, P]
     y_ref[...] = (y_diag + y_off).astype(y_ref.dtype).reshape(y_ref.shape)
 
-    decay = jnp.exp(a_cum[-1] - a_cum)           # [l]
-    bx = jax.lax.dot_general((b * decay[:, None]), x,
+    decay = jnp.exp(a_sum - a_cum_col)           # [l, 1]
+    bx = jax.lax.dot_general(b * decay, x,
                              (((0,), (0,)), ((), ())))          # [N, P]
-    state_ref[...] = state * jnp.exp(a_cum[-1]) + bx.T
+    state_ref[...] = state * jnp.exp(a_sum) + bx.T
 
     @pl.when(ci == nc - 1)
     def _done():
@@ -77,7 +85,9 @@ def ssd_scan(x, a, b, c, chunk: int = 128, *, interpret: bool = True):
     assert S % chunk == 0, (S, chunk)
     nc = S // chunk
     xh = x.transpose(0, 2, 1, 3).reshape(B, H, nc, chunk, P)
-    ah = a.transpose(0, 2, 1).reshape(B, H, nc, chunk)
+    # a unit dim ahead of the chunk axis: the block's last two dims are
+    # (1, chunk), both full array dims, as the TPU tiling rule requires
+    ah = a.transpose(0, 2, 1).reshape(B, H, nc, 1, chunk)
     bh = b.reshape(B, nc, chunk, N)
     ch = c.reshape(B, nc, chunk, N)
 
@@ -87,7 +97,8 @@ def ssd_scan(x, a, b, c, chunk: int = 128, *, interpret: bool = True):
         grid=(B, H, nc),
         in_specs=[
             pl.BlockSpec((1, 1, 1, chunk, P), lambda b_, h, ci: (b_, h, ci, 0, 0)),
-            pl.BlockSpec((1, 1, 1, chunk), lambda b_, h, ci: (b_, h, ci, 0)),
+            pl.BlockSpec((1, 1, 1, 1, chunk),
+                         lambda b_, h, ci: (b_, h, ci, 0, 0)),
             pl.BlockSpec((1, 1, chunk, N), lambda b_, h, ci: (b_, ci, 0, 0)),
             pl.BlockSpec((1, 1, chunk, N), lambda b_, h, ci: (b_, ci, 0, 0)),
         ],
@@ -100,7 +111,7 @@ def ssd_scan(x, a, b, c, chunk: int = 128, *, interpret: bool = True):
             jax.ShapeDtypeStruct((B, H, P, N), x.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(xh, ah, bh, ch)

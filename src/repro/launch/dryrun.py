@@ -190,8 +190,6 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool = False,
     t_compile = time.time() - t0
     mem = compiled.memory_analysis()
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):       # jax 0.4.x: one dict per device
-        ca = ca[0] if ca else {}
     coll = collective_bytes(compiled.as_text())
     rec = {
         "arch": arch, "shape": shape_name, "mesh": mesh_desc,

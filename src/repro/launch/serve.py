@@ -18,6 +18,7 @@ import numpy as np
 from repro.configs import get_config
 from repro.data import ByteTokenizer, PromptDataset, \
     synthetic_instruction_prompts
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import Model
 from repro.obs import MetricsRegistry
 from repro.rlhf import Rollout, live_device_bytes
@@ -36,6 +37,7 @@ def main():
     ap.add_argument("--metrics-out", default=None, metavar="PATH",
                     help="write a metrics-registry JSONL snapshot here")
     args = ap.parse_args()
+    use_compile_cache()
     reg = MetricsRegistry()
 
     cfg = get_config(args.arch)

@@ -22,6 +22,7 @@ from repro.checkpoint import save
 from repro.configs import get_config
 from repro.data import PromptDataset, SyntheticTextDataset, \
     synthetic_instruction_prompts
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import Model
 from repro.rlhf import RLHFConfig, RLHFTrainer
 from repro.rlhf.reward import make_target_token_reward
@@ -94,6 +95,7 @@ def main():
                     choices=("none", "after_inference", "after_training",
                              "after_all"))
     args = ap.parse_args()
+    use_compile_cache()
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = cfg.smoke()

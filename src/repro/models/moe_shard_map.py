@@ -36,18 +36,6 @@ from jax.sharding import PartitionSpec as P
 from repro.configs.base import ModelConfig, MoEConfig
 from repro.sharding import ctx
 
-try:
-    from jax import shard_map as _shard_map  # jax >= 0.7 name
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-import inspect as _inspect
-
-# the "don't verify replication" kwarg was renamed check_rep -> check_vma
-_SHARD_MAP_NO_CHECK = (
-    {"check_vma": False}
-    if "check_vma" in _inspect.signature(_shard_map).parameters
-    else {"check_rep": False})
 
 
 def _dp_axes(mesh):
@@ -170,12 +158,12 @@ def moe_fwd_shard_map(params, x, cfg: ModelConfig, *,
         y = jnp.zeros((t, D), x.dtype).at[tok_s].add(contrib)
         return y.reshape(x_loc.shape), aux
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(dpspec, "model", None), P(None, None),
                   P("model", None, None), P("model", None, None),
                   P("model", None, None)),
         out_specs=(P(dpspec, "model", None), P()),
-        **_SHARD_MAP_NO_CHECK)
+        check_vma=False)
     y, aux = fn(x, params["router"], w_in, w_gate, w_out)
     return y, aux * e.router_aux_coef

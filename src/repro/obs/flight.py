@@ -8,13 +8,18 @@ is caught in flight), it dumps a forensic JSON bundle: who owned how
 many bytes (from the attribution snapshot), the top-k live buffers with
 owner paths, and the phase history leading up to the breach.
 
-Capacity resolution, in order:
+Capacity is per device, and callers pass the live bytes of their fullest
+device. Resolution, in order:
   1. explicit ``capacity_bytes`` (tests, known HBM budgets);
-  2. ``device.memory_stats()["bytes_limit"]`` of the first local device
+  2. the smallest ``memory_stats()["bytes_limit"]`` over the local devices
      (real accelerators);
-  3. calibration fallback — the first ``check()`` latches its own live
-     bytes as capacity, so a *forced* low watermark (< 1.0) still
-     triggers deterministically on backends (CPU) that report no limit.
+  3. calibration fallback — the first ``check()`` made at a boundary (right
+     after a ``phase`` or ``serve_step`` note) latches its own live bytes
+     as capacity, so a *forced* low watermark (< 1.0) still triggers
+     deterministically on backends (CPU) that report no limit. Boundaries
+     follow the trainer's memory hygiene, so garbage that earlier work in
+     the process left uncollected is not latched into the budget; checks
+     before the first boundary (mid-phase samples) are skipped.
 
 The recorder is a pure observer: it never frees, never retries, never
 swallows the exception — ``record_oom`` captures and the caller
@@ -34,14 +39,17 @@ __all__ = ["FlightRecorder"]
 SCHEMA = "flight-recorder/v1"
 
 
+# context notes that mark a boundary: the calibration fallback latches there
+BOUNDARY_EVENTS = ("phase", "serve_step")
+
+
 def _device_bytes_limit() -> Optional[int]:
-    try:
-        import jax
-        stats = jax.local_devices()[0].memory_stats()
-        limit = int(stats.get("bytes_limit", 0))
-        return limit or None
-    except Exception:
-        return None
+    """Smallest per-device ``bytes_limit`` over the local devices, or None
+    where the backend reports none (CPU)."""
+    import jax
+    limits = [int((d.memory_stats() or {}).get("bytes_limit", 0))
+              for d in jax.local_devices()]
+    return min(limits) if limits and all(limits) else None
 
 
 class FlightRecorder:
@@ -50,8 +58,8 @@ class FlightRecorder:
     Parameters
     ----------
     watermark : fraction of capacity at which ``check()`` trips.
-    capacity_bytes : HBM budget; None -> device bytes_limit, else the
-        calibration fallback described in the module docstring.
+    capacity_bytes : per-device HBM budget; None -> device bytes_limit,
+        else the calibration fallback described in the module docstring.
     ring : max retained context events (spans/samples/offload events).
     top_k : live buffers listed in the dump.
     path : when set, each dump is also written to ``path`` (a single
@@ -91,12 +99,14 @@ class FlightRecorder:
         taken lazily (only on a trigger) so the steady-state cost of a
         check is two comparisons."""
         if not self._calibrated:
-            # CPU fallback: latch first observation as the budget so a
-            # forced watermark < 1.0 still has something to breach. The
-            # calibration sample itself cannot breach (it IS the budget);
-            # the next check that reaches watermark * this value trips.
-            self.capacity_bytes = max(int(live_bytes), 1)
-            self._calibrated = True
+            # CPU fallback: latch the first boundary observation as the
+            # budget so a forced watermark < 1.0 still has something to
+            # breach. The calibration sample itself cannot breach (it IS
+            # the budget); the next check that reaches watermark * this
+            # value trips.
+            if self.ring and self.ring[-1]["event"] in BOUNDARY_EVENTS:
+                self.capacity_bytes = max(int(live_bytes), 1)
+                self._calibrated = True
             return None
         if self.triggered.get("watermark"):
             return None

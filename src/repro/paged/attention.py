@@ -25,7 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 
 from repro.paged.paged_cache import gather_kv
 
@@ -170,7 +169,7 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, position, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, K, G, Dv), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(bt, pos, qh, kh, vh)
@@ -199,10 +198,10 @@ def paged_attention_decode(params, x, position, pool, block_tables, cfg, *,
     k = L.apply_rope(k, sin, cos)
     pool = append_decode(pool, k[:, 0], v[:, 0], block_tables, position)
     if use_kernel:
-        import jax as _jax
+        from repro.kernels.ops import interpret_kernels
         out = paged_decode_attention(
             q[:, 0], pool["k"], pool["v"], block_tables, position,
-            interpret=_jax.default_backend() != "tpu")
+            interpret=interpret_kernels())
     else:
         out = paged_attention_reference(q[:, 0], pool, block_tables, position)
     out = out.reshape(B, 1, -1)

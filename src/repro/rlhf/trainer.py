@@ -303,7 +303,8 @@ class PhaseMemoryManager:
         if fl is not None:
             fl.note("sample", phase=phase, live_bytes=rec["live_bytes"],
                     host_bytes=rec["host_bytes"])
-            fl.check(rec["live_bytes"], snapshot_fn=self._snapshot_for_dump,
+            fl.check(rec["live_bytes_per_device"],
+                     snapshot_fn=self._snapshot_for_dump,
                      phase=phase, source="rlhf")
 
     def boundary(self, phase: str, kind: str, *drop):
@@ -328,7 +329,8 @@ class PhaseMemoryManager:
             fl.note("phase", phase=phase, kind=kind,
                     live_bytes=rec["live_bytes"],
                     host_bytes=rec["host_bytes"])
-            fl.check(rec["live_bytes"], snapshot_fn=self._snapshot_for_dump,
+            fl.check(rec["live_bytes_per_device"],
+                     snapshot_fn=self._snapshot_for_dump,
                      phase=phase, source="rlhf")
         if self.offload is not None:
             self.offload.fetch_for_boundary(phase)
@@ -668,16 +670,14 @@ class RLHFTrainer:
         self.critic_step = make_train_step(self.critic, critic_cfg,
                                            kind="critic", lr=rl.critic_lr,
                                            shard=self.critic_plan)
+        # under a plan the state is built on its ZeRO layout (params + opt
+        # sharded over DP per stage), never whole on one device
         self.actor_state = init_train_state(self.actor, actor_cfg, ks[0],
-                                            self.actor_step.optimizer)
+                                            self.actor_step.optimizer,
+                                            plan=self.actor_plan)
         self.critic_state = init_train_state(self.critic, critic_cfg, ks[1],
-                                             self.critic_step.optimizer)
-        if self.actor_plan is not None:
-            # commit the ZeRO placement (params + opt sharded over DP per
-            # stage) — init values are unchanged, only their layout
-            self.actor_state = self.actor_plan.place_state(self.actor_state)
-            self.critic_state = self.critic_plan.place_state(
-                self.critic_state)
+                                             self.critic_step.optimizer,
+                                             plan=self.critic_plan)
         # reference = frozen copy of the (SFT) actor init; reward = frozen
         # copy of the critic init (same value-head structure — the reward
         # model is "a critic that stopped learning at preference time")

@@ -845,8 +845,8 @@ class ContinuousBatcher:
                     live_bytes=live_device_bytes(), source="serving")
             raise
         if self.flight is not None:
-            from repro.rlhf.trainer import live_device_bytes
-            live = live_device_bytes()
+            from repro.rlhf.trainer import per_device_live_bytes
+            live = per_device_live_bytes()
             self.flight.note("serve_step", step=self.steps,
                              live_bytes=live, queued=self.n_queued,
                              kv_reserved_bytes=self.kv_reserved_bytes())

@@ -184,6 +184,24 @@ class TreePlan:
             return opt
         return _place(opt, self.opt_specs, self.mesh)
 
+    def init_state(self, init_params, key, optimizer):
+        """A ``{"params", "opt", "step"}`` train state built straight onto
+        this plan's layout, so that no device ever holds the whole tree:
+        ``init_params(key)`` runs on the host CPU and only each device's
+        shard is transferred; the optimizer state is created sharded."""
+        host = jax.devices("cpu")[0]
+        with jax.default_device(host):
+            params = init_params(jax.device_put(key, host))
+        params = self.place_params(params)
+        if self.opt_specs is None:
+            opt = optimizer.init(params)
+        else:
+            opt = jax.jit(optimizer.init, out_shardings=jax.tree.map(
+                lambda s: NamedSharding(self.mesh, s), self.opt_specs,
+                is_leaf=_IS_SPEC))(params)
+        return {"params": params, "opt": opt,
+                "step": jnp.zeros((), jnp.int32)}
+
     def place_state(self, state):
         """Place a ``{"params", "opt", "step"}`` train state."""
         out = dict(state)

@@ -291,7 +291,12 @@ def make_train_step(model: Model, cfg: ModelConfig, *, lr: float = 3e-5,
     return train_step
 
 
-def init_train_state(model: Model, cfg: ModelConfig, key, optimizer):
+def init_train_state(model: Model, cfg: ModelConfig, key, optimizer,
+                     plan=None):
+    """Fresh train state; with a ``sharding.TreePlan`` it is built on the
+    plan's layout (``TreePlan.init_state``) instead of on one device."""
+    if plan is not None:
+        return plan.init_state(model.init, key, optimizer)
     params = model.init(key)
     return {"params": params, "opt": optimizer.init(params),
             "step": jnp.zeros((), jnp.int32)}

@@ -126,10 +126,15 @@ def test_flight_watermark_trigger_and_latch(tmp_path):
 
 def test_flight_calibration_fallback():
     """With no explicit capacity and no device bytes_limit info used, the
-    first check latches the budget and cannot itself breach; the next
-    check crossing watermark * budget trips."""
+    first check at a boundary latches the budget and cannot itself
+    breach; the next check crossing watermark * budget trips. Checks
+    before the first boundary (mid-phase samples) do not calibrate."""
     fl = FlightRecorder(watermark=0.5, ring=8)
     fl.capacity_bytes, fl._calibrated = None, False      # force fallback
+    fl.note("sample", phase="rollout_decode", live_bytes=5000)
+    assert fl.check(5000) is None                        # not a boundary
+    assert fl.capacity_bytes is None
+    fl.note("phase", phase="rollout", live_bytes=1000)
     assert fl.check(1000) is None                        # calibrates
     assert fl.capacity_bytes == 1000
     assert fl.check(400) is None                         # 0.4 < 0.5
