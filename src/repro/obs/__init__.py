@@ -14,8 +14,10 @@ span tracer per run, written out as
 phase carrying measured bytes AND the traced allocator-simulator's
 prediction (the sim-vs-measured delta); ``OffloadExecutor`` emits
 park/fetch spans with PCIe bytes; ``serving.ContinuousBatcher`` emits
-page-pool occupancy, preemption/CoW counters, admission latency and
-tokens/sec. See DESIGN.md §4 for the span taxonomy and metric names.
+page-pool occupancy, preemption/CoW counters and admission latency, and
+keeps its ``serve.*`` spans (written into the profiler's trace with or
+without a bundle). See DESIGN.md §4 for the span taxonomy and metric
+names.
 """
 from __future__ import annotations
 
@@ -30,11 +32,12 @@ from repro.obs.attribution import (AttributionSnapshot, MemoryAttributor,
 from repro.obs.flight import FlightRecorder
 from repro.obs.metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                                global_registry, set_global_registry)
-from repro.obs.tracer import Span, SpanTracer
+from repro.obs.tracer import ProfilerSpans, Span, SpanTracer
 
 __all__ = ["AttributionSnapshot", "Counter", "FlightRecorder", "Gauge",
-           "Histogram", "MemoryAttributor", "MetricsRegistry", "RunTelemetry",
-           "Span", "SpanTracer", "compiled_memory_stats", "global_registry",
+           "Histogram", "MemoryAttributor", "MetricsRegistry",
+           "ProfilerSpans", "RunTelemetry", "Span", "SpanTracer",
+           "compiled_memory_stats", "global_registry",
            "record_compiled_memory", "set_global_registry"]
 
 
@@ -60,13 +63,13 @@ class RunTelemetry:
     flight: Optional[FlightRecorder] = None
 
     @classmethod
-    def create(cls, *, sim_delta: bool = True, jax_annotate: bool = False,
+    def create(cls, *, sim_delta: bool = True,
                registry: Optional[MetricsRegistry] = None,
                attribution: Optional[MemoryAttributor] = None,
                flight: Optional[FlightRecorder] = None,
                **meta) -> "RunTelemetry":
         return cls(registry=registry or MetricsRegistry(),
-                   tracer=SpanTracer(jax_annotate=jax_annotate),
+                   tracer=SpanTracer(),
                    sim_delta=sim_delta, meta=dict(meta),
                    attribution=attribution, flight=flight)
 

@@ -9,16 +9,25 @@ attaches the traced allocator-simulator's predicted peak for the phase so
 the *sim-vs-measured delta* rides every phase span (see
 ``rlhf.trainer.PhaseMemoryManager``).
 
-Three ways to record:
+Every ``begin``/``end`` pair (and ``span``) also enters a
+``jax.profiler.TraceAnnotation`` that carries the span's args as event
+stats, so while a ``jax.profiler`` capture runs the span sits in the
+profiler's host trace, on the clock of the device's events, and idle
+device time can be charged to it. Outside a capture an annotation costs about a microsecond.
+``ProfilerSpans`` is that bridge alone: it keeps nothing in memory, for
+a subsystem that runs with no telemetry bundle (the serving batcher).
 
-  * ``begin(name)`` / ``end()`` — stack-nested, for intervals whose
-    endpoints the caller controls (the per-iteration parent span, offload
-    park/fetch windows);
+Ways to record:
+
+  * ``begin(name)`` / ``end()`` / ``span(name)`` — stack-nested, on the
+    calling thread, for intervals whose endpoints the caller controls;
+    ``Span.set(**args)`` adds stats to an open span;
   * ``complete(name, t0, t1)`` — retroactive, for intervals delimited by
-    events (phase boundaries: a phase's start is the previous boundary);
+    events (the trainer's phases: a phase's start is the previous
+    boundary); kept in memory only, it never reaches the profiler;
   * ``instant(name)`` / ``sample(values)`` — point events and counter
     tracks (the live device/host-bytes timeline Perfetto renders as an
-    area chart).
+    area chart), in memory only.
 
 Export targets:
 
@@ -30,14 +39,15 @@ Export targets:
   * :meth:`write_jsonl` — one JSON object per span/instant/sample, the
     file ``launch/report.py`` renders without recomputation.
 
+Both give times in microseconds since the tracer's epoch and record its
+wall-clock anchor, ``t0_wall`` (seconds since the Unix epoch, taken with
+the epoch). The profiler stamps host events in wall-clock nanoseconds
+from its capture's ``profile_start_time``; ``t0_wall * 1e6 + ts_us`` puts
+a span on that clock.
+
 Self-accounting: every public recording method adds its own elapsed time
 to ``self_time_s``, so a run can report the telemetry tax directly
 (``overhead_fraction(wall_s)``) instead of relying on noisy A/B timing.
-
-``jax_annotate=True`` additionally brackets every ``begin``/``end`` span
-in a ``jax.profiler.TraceAnnotation`` so the spans line up with XLA's own
-rows when a ``jax.profiler.trace()`` capture is active; it is a no-op
-when the profiler isn't available.
 """
 from __future__ import annotations
 
@@ -47,6 +57,8 @@ import time
 from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 # stable tid per category → one named row per subsystem in Perfetto
 _CATEGORY_TIDS = {"iteration": 1, "phase": 2, "offload": 3, "serving": 4,
@@ -61,6 +73,17 @@ class Span:
     dur_us: float = 0.0
     depth: int = 0
     args: Dict[str, Any] = field(default_factory=dict)
+    # the profiler event of a span opened by ``begin``, until ``end``
+    annotation: Optional[TraceAnnotation] = field(default=None, repr=False,
+                                                  compare=False)
+
+    def set(self, **args) -> None:
+        """Add stats to the span, and to its profiler event while open.
+        Host values only: the profiler formats a stat as it is recorded,
+        so a device array here would wait for the device."""
+        self.args.update(args)
+        if self.annotation is not None and args:
+            self.annotation.set_metadata(**args)
 
     def record(self) -> dict:
         return {"type": "span", "name": self.name, "cat": self.cat,
@@ -68,19 +91,14 @@ class Span:
                 "depth": self.depth, "args": self.args}
 
 
-class SpanTracer:
-    def __init__(self, *, jax_annotate: bool = False):
-        self.t0_wall = time.time()           # epoch anchor for export
-        self._t0 = time.perf_counter()
-        self.spans: List[Span] = []          # finished, in completion order
-        self.instants: List[dict] = []
-        self.samples: List[dict] = []        # counter-track samples
-        self._stack: List[Span] = []
-        self._annotations: List[Any] = []
-        self.jax_annotate = jax_annotate
-        self.self_time_s = 0.0
+class ProfilerSpans:
+    """Stack-nested spans written into the profiler's trace and nowhere
+    else: each is a ``TraceAnnotation`` carrying its args as stats."""
 
-    # ------------------------------------------------------------- clock
+    def __init__(self):
+        self._t0 = time.perf_counter()
+        self._stack: List[Span] = []
+
     def now_us(self) -> float:
         return (time.perf_counter() - self._t0) * 1e6
 
@@ -88,27 +106,21 @@ class SpanTracer:
     def depth(self) -> int:
         return len(self._stack)
 
-    # ----------------------------------------------------------- recording
     def begin(self, name: str, cat: str = "misc", **args) -> Span:
-        t = time.perf_counter()
+        ann = TraceAnnotation(name, **args)
+        ann.__enter__()
         sp = Span(name, cat, self.now_us(), depth=len(self._stack),
-                  args=dict(args))
+                  args=args, annotation=ann)
         self._stack.append(sp)
-        if self.jax_annotate:
-            self._annotations.append(self._enter_annotation(name))
-        self.self_time_s += time.perf_counter() - t
         return sp
 
     def end(self, **args) -> Span:
-        t = time.perf_counter()
-        assert self._stack, "SpanTracer.end() with no open span"
+        assert self._stack, "end() with no open span"
         sp = self._stack.pop()
-        if self.jax_annotate and self._annotations:
-            self._exit_annotation(self._annotations.pop())
+        sp.set(**args)
+        sp.annotation.__exit__(None, None, None)
+        sp.annotation = None
         sp.dur_us = self.now_us() - sp.ts_us
-        sp.args.update(args)
-        self.spans.append(sp)
-        self.self_time_s += time.perf_counter() - t
         return sp
 
     @contextmanager
@@ -118,6 +130,33 @@ class SpanTracer:
             yield sp
         finally:
             self.end()
+
+
+class SpanTracer(ProfilerSpans):
+    """``ProfilerSpans`` that also keeps every span, instant and sample in
+    memory for export."""
+
+    def __init__(self):
+        self.t0_wall = time.time()           # epoch anchor for export
+        super().__init__()
+        self.spans: List[Span] = []          # finished, in completion order
+        self.instants: List[dict] = []
+        self.samples: List[dict] = []        # counter-track samples
+        self.self_time_s = 0.0
+
+    # ----------------------------------------------------------- recording
+    def begin(self, name: str, cat: str = "misc", **args) -> Span:
+        t = time.perf_counter()
+        sp = super().begin(name, cat, **args)
+        self.self_time_s += time.perf_counter() - t
+        return sp
+
+    def end(self, **args) -> Span:
+        t = time.perf_counter()
+        sp = super().end(**args)
+        self.spans.append(sp)
+        self.self_time_s += time.perf_counter() - t
+        return sp
 
     def complete(self, name: str, cat: str, ts_us: float, dur_us: float,
                  **args) -> Span:
@@ -150,25 +189,6 @@ class SpanTracer:
                              "values": {k: float(v)
                                         for k, v in values.items()}})
         self.self_time_s += time.perf_counter() - t
-
-    # ------------------------------------------------- jax.profiler bridge
-    @staticmethod
-    def _enter_annotation(name: str):
-        try:
-            from jax.profiler import TraceAnnotation
-            ann = TraceAnnotation(name)
-            ann.__enter__()
-            return ann
-        except Exception:
-            return None
-
-    @staticmethod
-    def _exit_annotation(ann) -> None:
-        if ann is not None:
-            try:
-                ann.__exit__(None, None, None)
-            except Exception:
-                pass
 
     # -------------------------------------------------------------- export
     def overhead_fraction(self, wall_s: float) -> float:

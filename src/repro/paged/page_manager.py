@@ -110,9 +110,6 @@ class PageManager:
         """Zero-ref pages parked in the prefix-cache LRU (evictable)."""
         return len(self._lru)
 
-    def cached_bytes(self) -> int:
-        return len(self._lru) * self.page_bytes
-
     def _deindex(self, p: int):
         h = self._page_hash[p]
         if h is not None:

@@ -51,9 +51,15 @@ Multi-tenant serving features (paged backend):
     is admitted in bounded time. Preemption picks the victim holding the
     most *exclusively owned* pages (shared prefix pages survive their
     victim and keep serving siblings).
+
+Every submit and step writes ``serve.*`` spans with their counts as
+stats (DESIGN.md §4) into the profiler's trace, telemetry bundle or not;
+a bundle also keeps them. The stats are host values, read from state the
+step keeps anyway, so the spans add no device sync.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from collections import OrderedDict, deque
@@ -65,6 +71,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.models import Model
+from repro.obs.tracer import ProfilerSpans
 from repro.rlhf.rollout import place_kv_tp, sample_token, spec_verify_step
 from repro.sharding import ctx as shctx
 from repro.serving.buckets import BucketLadder, CompileCache
@@ -78,10 +85,14 @@ class Request:
     out_tokens: List[int] = dataclasses.field(default_factory=list)
     done: bool = False
     n_preempted: int = 0
-    t_submit: float = 0.0        # wall time at submit(); admission latency
+    t_submit: float = 0.0        # perf_counter at submit(); admission latency
     tenant: str = "default"
     step_submit: int = 0         # batcher step at submit(); aging clock
     n_cached_tokens: int = 0     # prompt tokens served from the prefix cache
+    # on t_submit's clock, at the first admission (a re-admission after
+    # preemption keeps them): admitted, and its first token on the host
+    t_admitted: Optional[float] = None
+    t_first_token: Optional[float] = None
 
 
 class ContinuousBatcher:
@@ -107,6 +118,10 @@ class ContinuousBatcher:
         assert not (prefix_cache and cache_backend != "paged"), \
             "prefix caching needs the paged backend"
         self.telemetry = telemetry          # obs.RunTelemetry | None
+        # the serve.* spans go into the profiler's trace on every step; a
+        # telemetry bundle also keeps them (DESIGN.md §4)
+        self.spans = telemetry.tracer if telemetry is not None \
+            else ProfilerSpans()
         # memory observatory: owner registration for the attribution
         # engine, the run's flight recorder, and per-jit-program
         # compiled-memory stats joined to CompileCache keys
@@ -361,15 +376,24 @@ class ContinuousBatcher:
         if stats is not None:
             self.compiled_memory[key] = stats
 
-    def _record_key(self, key, fn=None, *args) -> None:
-        hit = self.compile_cache.lookup(key)
-        if self.telemetry is not None and not hit:
+    def _compiling(self, key, fn=None, *args):
+        """Count ``key`` in the compile cache; returns the context to run
+        its call in. A miss after warm-up is a recompile, and the context
+        is its ``serve.recompile`` span, so the trace shows the step and
+        the call that compiled. With telemetry a miss is also an instant,
+        and ``fn(*args)`` has its compiled memory noted."""
+        if self.compile_cache.lookup(key):
+            return contextlib.nullcontext()
+        name = ":".join(str(k) for k in key)
+        if self.telemetry is not None:
             self.telemetry.tracer.instant(
-                f"compile:{':'.join(str(k) for k in key)}", "serving",
+                f"compile:{name}", "serving",
                 recompile=self.compile_cache.warmed)
-            # a post-warmup miss is a recompile: account its memory too
             if fn is not None:
                 self._note_compiled(key, fn, *args)
+        if not self.compile_cache.warmed:
+            return contextlib.nullcontext()
+        return self.spans.span("serve.recompile", "serving", key=name)
 
     def submit(self, prompt: np.ndarray, max_new_tokens: int,
                tenant: str = "default") -> Request:
@@ -380,22 +404,27 @@ class ContinuousBatcher:
             raise ValueError(
                 f"request needs {len(prompt) + max_new_tokens} tokens, "
                 f"capacity is {self.capacity}")
-        req = Request(self._next_rid, prompt, max_new_tokens,
-                      t_submit=time.perf_counter(), tenant=tenant,
-                      step_submit=self.steps)
-        self._next_rid += 1
-        q = self.queues.get(tenant)
-        if q is None:
-            q = self.queues[tenant] = deque()
-        if not q:
-            # a tenant going from idle to backlogged re-enters at the
-            # current service frontier: it must not bank idle time and
-            # then monopolise admission catching up
-            floor = min((self._vtime.get(t, 0.0)
-                         for t, tq in self.queues.items() if tq and t != tenant),
-                        default=self._vtime.get(tenant, 0.0))
-            self._vtime[tenant] = max(self._vtime.get(tenant, 0.0), floor)
-        q.append(req)
+        rid = self._next_rid
+        with self.spans.span("serve.submit", "serving", rid=rid,
+                             prompt=len(prompt)):
+            req = Request(rid, prompt, max_new_tokens,
+                          t_submit=time.perf_counter(), tenant=tenant,
+                          step_submit=self.steps)
+            self._next_rid += 1
+            q = self.queues.get(tenant)
+            if q is None:
+                q = self.queues[tenant] = deque()
+            if not q:
+                # a tenant going from idle to backlogged re-enters at the
+                # current service frontier: it must not bank idle time and
+                # then monopolise admission catching up
+                floor = min((self._vtime.get(t, 0.0)
+                             for t, tq in self.queues.items()
+                             if tq and t != tenant),
+                            default=self._vtime.get(tenant, 0.0))
+                self._vtime[tenant] = max(self._vtime.get(tenant, 0.0),
+                                          floor)
+            q.append(req)
         if self.telemetry is not None:
             self.telemetry.registry.counter(
                 "serving_requests_total", "requests submitted").inc()
@@ -498,91 +527,43 @@ class ContinuousBatcher:
             full = np.concatenate(
                 [req.prompt, np.asarray(req.out_tokens, np.int32)])
             P = len(full)
-            n_cached = 0
-            if self.backend == "paged" and self.prefix_cache:
-                # gate admission on pages for the non-cached tail + first
-                # decode token; matched pages are reused, not claimed
-                if not self.pm.can_allocate_prefix(full, 1):
-                    break
-                self.queues[tenant].popleft()
-                _, n_cached = self.pm.allocate_prefix(req.rid, full)
-                suffix = full[n_cached:]
-                # bucket on the *suffix* length — a hash hit compiles and
-                # computes only the tail
-                Sb = self.prefill_ladder.fit(len(suffix)) \
-                    if self.prefill_ladder else len(suffix)
-                padded = np.zeros(Sb, np.int32)
-                padded[:len(suffix)] = suffix
-                lens = jnp.full((1,), P, jnp.int32)
-                start = jnp.full((1,), n_cached, jnp.int32)
-                bt_row = self._block_tables_for([req.rid])
-                pb = {"tokens": jnp.asarray(padded)[None]}
-                lg, self.pools, h1 = self._prefill_suffix(
-                    self.params, pb, self.pools, bt_row, start, lens)
-                self.pm.commit_prefix(req.rid, full)
-                self._prefix_tokens_hit += n_cached
-                self._prefix_tokens_total += P
-                req.n_cached_tokens = n_cached
-                self._record_key(("prefill", self.backend, Sb),
-                                 self._prefill_suffix, self.params, pb,
-                                 self.pools, bt_row, start, lens)
-            else:
-                # pad the prompt up to its capture bucket; the per-row
-                # ``lengths`` makes the padding exactly invisible
-                Sb = self.prefill_ladder.fit(P) if self.prefill_ladder \
-                    else P
-                padded = np.zeros(Sb, np.int32)
-                padded[:P] = full
-                lens = jnp.full((1,), P, jnp.int32)
-                if self.backend == "paged":
-                    # gate admission on pages for the prefill + first decode
-                    if not self.pm.can_allocate(P + 1):
-                        break
-                    self.queues[tenant].popleft()
-                    self.pm.allocate(req.rid, P)
-                    bt_row = self._block_tables_for([req.rid])
-                    lg, self.pools, h1 = self._prefill(
-                        self.params, {"tokens": jnp.asarray(padded)[None]},
-                        self.pools, bt_row, lens)
-                else:
-                    self.queues[tenant].popleft()
-                    if self._rich_prefill:
-                        lg, caches1, h1 = self._prefill(
-                            self.params,
-                            {"tokens": jnp.asarray(padded)[None]}, lens)
-                    else:
-                        lg, caches1 = self._prefill(
-                            self.params,
-                            {"tokens": jnp.asarray(padded)[None]})
-                        h1 = None
-                    # write slot s of the pool from the batch-of-1 prefill
-                    self.caches["segments"] = jax.tree.map(
-                        lambda pool, new: pool.at[:, s:s + 1].set(new),
-                        self.caches["segments"], caches1["segments"])
-                pk = ("prefill", self.backend, Sb)
-                pb = {"tokens": jnp.asarray(padded)[None]}
-                if self.backend == "paged":
-                    self._record_key(pk, self._prefill, self.params, pb,
-                                     self.pools, bt_row, lens)
-                elif self._rich_prefill:
-                    self._record_key(pk, self._prefill, self.params, pb,
-                                     lens)
-                else:
-                    self._record_key(pk, self._prefill, self.params, pb)
-            # charge the tenant's virtual time for the service footprint
-            # it just claimed (prompt + remaining generation budget)
-            cost = P + req.max_new_tokens - len(req.out_tokens)
-            self._vtime[tenant] = self._vtime.get(tenant, 0.0) \
-                + cost / max(self.tenant_weights.get(tenant, 1.0), 1e-9)
-            self.key, k = jax.random.split(self.key)
-            tok, _ = sample_token(k, lg, temperature=self.temperature,
-                                  top_k=self.top_k)
-            self.active[s] = req
-            self.pos[s] = P
-            self.last_tok[s] = int(tok[0])
-            req.out_tokens.append(int(tok[0]))
-            if self.spec_decode:
-                self.h_last = self.h_last.at[s].set(h1[0])
+            # gate paged admission on pages for the prefill (with the
+            # prefix cache, its non-cached tail) + first decode token
+            if self.backend == "paged" and not (
+                    self.pm.can_allocate_prefix(full, 1) if self.prefix_cache
+                    else self.pm.can_allocate(P + 1)):
+                break
+            self.queues[tenant].popleft()
+            # a re-admission's wait is a preemption artifact, not queueing
+            first = req.n_preempted == 0
+            waited = {}
+            if first:
+                req.t_admitted = time.perf_counter()
+                waited["wait_us"] = round(
+                    (req.t_admitted - req.t_submit) * 1e6)
+            with self.spans.span("serve.admit", "serving", rid=req.rid,
+                                 prompt=P, **waited) as sp:
+                with self.spans.span("serve.prefill", "serving"):
+                    lg, h1, Sb, n_cached = self._prefill_into(s, req, full)
+                sp.set(bucket=Sb, cached=n_cached)
+                # charge the tenant's virtual time for the service
+                # footprint it just claimed (prompt + remaining budget)
+                cost = P + req.max_new_tokens - len(req.out_tokens)
+                self._vtime[tenant] = self._vtime.get(tenant, 0.0) \
+                    + cost / max(self.tenant_weights.get(tenant, 1.0), 1e-9)
+                with self.spans.span("serve.first_token", "serving"):
+                    self.key, k = jax.random.split(self.key)
+                    tok, _ = sample_token(k, lg, temperature=self.temperature,
+                                          top_k=self.top_k)
+                    tok = int(tok[0])
+                if first:
+                    req.t_first_token = time.perf_counter()
+                self.active[s] = req
+                self.pos[s] = P
+                self.last_tok[s] = tok
+                req.out_tokens.append(tok)
+                if self.spec_decode:
+                    self.h_last = self.h_last.at[s].set(h1[0])
             if self.telemetry is not None:
                 reg = self.telemetry.registry
                 reg.counter("serving_admissions_total",
@@ -592,28 +573,81 @@ class ContinuousBatcher:
                         "paged_prefix_hit_tokens_total",
                         "prompt tokens served from the prefix cache").inc(
                         n_cached)
-                # latency only for first admission: a re-admit's wait is
-                # a preemption artifact, not queueing delay
-                if req.n_preempted == 0:
+                if first:
                     reg.histogram(
                         "serving_admission_latency_s",
                         "submit -> first admission wall time").observe(
-                        time.perf_counter() - req.t_submit)
+                        req.t_admitted - req.t_submit)
+
+    def _prefill_into(self, s: int, req: Request, full: np.ndarray):
+        """Dispatch the prefill of ``full`` for ``req`` into slot ``s``
+        (paged: its pages are claimed here; the caller checked they fit).
+        Returns the logits, the trunk state (None where the program gives
+        none), the prompt bucket and the tokens the prefix cache served."""
+        P = len(full)
+        lens = jnp.full((1,), P, jnp.int32)
+        if self.backend == "paged" and self.prefix_cache:
+            _, n_cached = self.pm.allocate_prefix(req.rid, full)
+            suffix = full[n_cached:]
+            # bucket on the *suffix* length — a hash hit compiles and
+            # computes only the tail
+            Sb = self.prefill_ladder.fit(len(suffix)) \
+                if self.prefill_ladder else len(suffix)
+            padded = np.zeros(Sb, np.int32)
+            padded[:len(suffix)] = suffix
+            start = jnp.full((1,), n_cached, jnp.int32)
+            args = (self.params, {"tokens": jnp.asarray(padded)[None]},
+                    self.pools, self._block_tables_for([req.rid]), start,
+                    lens)
+            with self._compiling(("prefill", self.backend, Sb),
+                                 self._prefill_suffix, *args):
+                lg, self.pools, h1 = self._prefill_suffix(*args)
+            self.pm.commit_prefix(req.rid, full)
+            self._prefix_tokens_hit += n_cached
+            self._prefix_tokens_total += P
+            req.n_cached_tokens = n_cached
+            return lg, h1, Sb, n_cached
+        # pad the prompt up to its capture bucket; the per-row ``lengths``
+        # makes the padding exactly invisible
+        Sb = self.prefill_ladder.fit(P) if self.prefill_ladder else P
+        padded = np.zeros(Sb, np.int32)
+        padded[:P] = full
+        pb = {"tokens": jnp.asarray(padded)[None]}
+        key = ("prefill", self.backend, Sb)
+        if self.backend == "paged":
+            self.pm.allocate(req.rid, P)
+            args = (self.params, pb, self.pools,
+                    self._block_tables_for([req.rid]), lens)
+            with self._compiling(key, self._prefill, *args):
+                lg, self.pools, h1 = self._prefill(*args)
+            return lg, h1, Sb, 0
+        args = (self.params, pb, lens) if self._rich_prefill \
+            else (self.params, pb)
+        with self._compiling(key, self._prefill, *args):
+            out = self._prefill(*args)
+        lg, caches1, h1 = out if self._rich_prefill else (*out, None)
+        # write slot s of the pool from the batch-of-1 prefill
+        self.caches["segments"] = jax.tree.map(
+            lambda pool, new: pool.at[:, s:s + 1].set(new),
+            self.caches["segments"], caches1["segments"])
+        return lg, h1, Sb, 0
 
     def _retire(self):
         done = []
-        for s, req in enumerate(self.active):
-            if req is None:
-                continue
-            hit_eos = (self.eos_id is not None
-                       and req.out_tokens
-                       and req.out_tokens[-1] == self.eos_id)
-            if hit_eos or len(req.out_tokens) >= req.max_new_tokens:
-                req.done = True
-                done.append(req)
-                if self.backend == "paged":
-                    self.pm.free_seq(req.rid)   # pages back to the pool
-                self.active[s] = None           # slot freed
+        with self.spans.span("serve.retire", "serving") as sp:
+            for s, req in enumerate(self.active):
+                if req is None:
+                    continue
+                hit_eos = (self.eos_id is not None
+                           and req.out_tokens
+                           and req.out_tokens[-1] == self.eos_id)
+                if hit_eos or len(req.out_tokens) >= req.max_new_tokens:
+                    req.done = True
+                    done.append(req)
+                    if self.backend == "paged":
+                        self.pm.free_seq(req.rid)   # pages back to the pool
+                    self.active[s] = None           # slot freed
+            sp.set(done=len(done))
         return done
 
     def _grow_pages(self, n: int = 1):
@@ -621,17 +655,22 @@ class ContinuousBatcher:
         (spec decode grows by ``spec_k + 1`` before the verify forward);
         preempt the youngest request when the pool is dry."""
         from repro.paged import PagePoolExhausted
-        for s in range(self.B):
-            req = self.active[s]
-            if req is None:
-                continue
-            while True:
-                try:
-                    self._apply_copies(self.pm.append_tokens(req.rid, n))
-                    break
-                except PagePoolExhausted:
-                    if not self._preempt_youngest(protect=s):
-                        raise
+        with self.spans.span("serve.grow_pages", "serving") as sp:
+            claimed, preempted = self.pm.stats.n_page_alloc, 0
+            for s in range(self.B):
+                req = self.active[s]
+                if req is None:
+                    continue
+                while True:
+                    try:
+                        self._apply_copies(self.pm.append_tokens(req.rid, n))
+                        break
+                    except PagePoolExhausted:
+                        if not self._preempt_youngest(protect=s):
+                            raise
+                        preempted += 1
+            sp.set(claimed=self.pm.stats.n_page_alloc - claimed,
+                   preempted=preempted)
 
     # -- decode flavours -----------------------------------------------------
     def _append_emitted(self, s: int, emitted_toks) -> int:
@@ -652,84 +691,101 @@ class ContinuousBatcher:
         self.last_tok[s] = req.out_tokens[-1]
         return taken
 
-    def _vanilla_decode(self, live_slots: List[int]) -> None:
-        self.key, k = jax.random.split(self.key)
+    def _decode_rows(self, n_live: int) -> int:
+        """Rows the decode program computes for ``n_live`` live slots: the
+        live-slot bucket (paged with a ladder), else every slot."""
         if self.backend == "paged" and self.slot_ladder is not None:
-            # gather live rows into a slot bucket; pad rows are idle
-            # (position -1 -> dropped writes, masked sampling)
-            nb = self.slot_ladder.fit(len(live_slots))
-            tok_in = np.zeros(nb, np.int64)
-            pos_in = np.full(nb, -1, np.int64)
-            tok_in[:len(live_slots)] = self.last_tok[live_slots]
-            pos_in[:len(live_slots)] = self.pos[live_slots]
-            sids = [self.active[s].rid for s in live_slots]
-            sids += [None] * (nb - len(live_slots))
-            live_v = jnp.asarray(np.arange(nb) < len(live_slots))
-            self._record_key(self._decode_key(nb))
-            tok, self.pools = self._decode(
-                self.params, self.pools, jnp.asarray(tok_in, jnp.int32),
-                jnp.asarray(pos_in, jnp.int32), self._block_tables_for(sids),
-                k, live_v)
+            return self.slot_ladder.fit(n_live)
+        return self.B
+
+    def _decode_span(self, live_slots: List[int], rows: int):
+        """The ``serve.decode`` span: rows computed and live, the KV
+        positions its attention gathers and those the live rows hold."""
+        per_row = self.max_blocks * self.page_size \
+            if self.backend == "paged" else self.capacity
+        return self.spans.span(
+            "serve.decode", "serving", rows=rows, live=len(live_slots),
+            kv_gathered=rows * per_row,
+            kv_live=int(self.pos[live_slots].sum()) + len(live_slots))
+
+    def _vanilla_decode(self, live_slots: List[int]) -> None:
+        nb = self._decode_rows(len(live_slots))
+        with self._decode_span(live_slots, nb):
+            self.key, k = jax.random.split(self.key)
+            if self.backend == "paged" and self.slot_ladder is not None:
+                # gather live rows into a slot bucket; pad rows are idle
+                # (position -1 -> dropped writes, masked sampling)
+                tok_in = np.zeros(nb, np.int64)
+                pos_in = np.full(nb, -1, np.int64)
+                tok_in[:len(live_slots)] = self.last_tok[live_slots]
+                pos_in[:len(live_slots)] = self.pos[live_slots]
+                sids = [self.active[s].rid for s in live_slots]
+                sids += [None] * (nb - len(live_slots))
+                live_v = jnp.asarray(np.arange(nb) < len(live_slots))
+                with self._compiling(self._decode_key(nb)):
+                    tok, self.pools = self._decode(
+                        self.params, self.pools,
+                        jnp.asarray(tok_in, jnp.int32),
+                        jnp.asarray(pos_in, jnp.int32),
+                        self._block_tables_for(sids), k, live_v)
+                rows = range(len(live_slots))
+            else:
+                live = np.array([r is not None for r in self.active])
+                tok_in = jnp.asarray(self.last_tok, jnp.int32)
+                pos_in = jnp.asarray(self.pos, jnp.int32)
+                with self._compiling(self._decode_key(nb)):
+                    if self.backend == "paged":
+                        pos_in = jnp.where(jnp.asarray(live), pos_in, -1)
+                        tok, self.pools = self._decode(
+                            self.params, self.pools, tok_in, pos_in,
+                            self._slot_block_tables(), k, jnp.asarray(live))
+                    else:
+                        tok, self.caches = self._decode(
+                            self.params, self.caches, tok_in, pos_in, k,
+                            jnp.asarray(live))
+                rows = live_slots
+        with self.spans.span("serve.readback", "serving"):
             tok = np.asarray(tok)
-            for j, s in enumerate(live_slots):
-                self._append_emitted(s, [tok[j]])
-            return
-        live = np.array([r is not None for r in self.active])
-        tok_in = jnp.asarray(self.last_tok, jnp.int32)
-        pos_in = jnp.asarray(self.pos, jnp.int32)
-        self._record_key(self._decode_key(self.B))
-        if self.backend == "paged":
-            pos_in = jnp.where(jnp.asarray(live), pos_in, -1)
-            tok, self.pools = self._decode(
-                self.params, self.pools, tok_in, pos_in,
-                self._slot_block_tables(), k, jnp.asarray(live))
-        else:
-            tok, self.caches = self._decode(
-                self.params, self.caches, tok_in, pos_in, k,
-                jnp.asarray(live))
-        tok = np.asarray(tok)
-        for s in live_slots:
-            self._append_emitted(s, [tok[s]])
+        for j, s in zip(rows, live_slots):
+            self._append_emitted(s, [tok[j]])
 
     def _spec_decode_step(self, live_slots: List[int]) -> None:
         """Draft + one batched verify + accept for all live slots."""
         n_live = len(live_slots)
-        if self.backend == "paged" and self.slot_ladder is not None:
-            nb = self.slot_ladder.fit(n_live)
-        elif self.backend == "paged":
-            nb = self.B
-        else:
-            nb = self.B
-        if self.backend == "paged":
-            tok_in = np.zeros(nb, np.int64)
-            pos_in = np.full(nb, -1, np.int64)
-            tok_in[:n_live] = self.last_tok[live_slots]
-            pos_in[:n_live] = self.pos[live_slots]
-            sids = [self.active[s].rid for s in live_slots]
-            sids += [None] * (nb - n_live)
-            live_v = jnp.asarray(np.arange(nb) < n_live)
-            h_in = self.h_last[np.asarray(live_slots, np.int32)]
-            if nb > n_live:
-                h_in = jnp.concatenate(
-                    [h_in, jnp.zeros((nb - n_live,) + h_in.shape[1:],
-                                     h_in.dtype)])
-            self._record_key(self._decode_key(nb))
-            greedy, _lp, n_acc, h_new, self.pools = self._spec(
-                self.params, self.pools, h_in,
-                jnp.asarray(tok_in, jnp.int32), jnp.asarray(pos_in, jnp.int32),
-                self._block_tables_for(sids), live_v)
-            rows = range(n_live)
-        else:
-            live = np.array([r is not None for r in self.active])
-            pos_in = np.where(live, self.pos, -1)
-            self._record_key(self._decode_key(nb))
-            greedy, _lp, n_acc, h_new, self.caches = self._spec(
-                self.params, self.caches, self.h_last,
-                jnp.asarray(self.last_tok, jnp.int32),
-                jnp.asarray(pos_in, jnp.int32), jnp.asarray(live))
-            rows = live_slots
-        greedy = np.asarray(greedy)
-        n_acc_np = np.asarray(n_acc)
+        nb = self._decode_rows(n_live)
+        with self._decode_span(live_slots, nb):
+            if self.backend == "paged":
+                tok_in = np.zeros(nb, np.int64)
+                pos_in = np.full(nb, -1, np.int64)
+                tok_in[:n_live] = self.last_tok[live_slots]
+                pos_in[:n_live] = self.pos[live_slots]
+                sids = [self.active[s].rid for s in live_slots]
+                sids += [None] * (nb - n_live)
+                live_v = jnp.asarray(np.arange(nb) < n_live)
+                h_in = self.h_last[np.asarray(live_slots, np.int32)]
+                if nb > n_live:
+                    h_in = jnp.concatenate(
+                        [h_in, jnp.zeros((nb - n_live,) + h_in.shape[1:],
+                                         h_in.dtype)])
+                with self._compiling(self._decode_key(nb)):
+                    greedy, _lp, n_acc, h_new, self.pools = self._spec(
+                        self.params, self.pools, h_in,
+                        jnp.asarray(tok_in, jnp.int32),
+                        jnp.asarray(pos_in, jnp.int32),
+                        self._block_tables_for(sids), live_v)
+                rows = range(n_live)
+            else:
+                live = np.array([r is not None for r in self.active])
+                pos_in = np.where(live, self.pos, -1)
+                with self._compiling(self._decode_key(nb)):
+                    greedy, _lp, n_acc, h_new, self.caches = self._spec(
+                        self.params, self.caches, self.h_last,
+                        jnp.asarray(self.last_tok, jnp.int32),
+                        jnp.asarray(pos_in, jnp.int32), jnp.asarray(live))
+                rows = live_slots
+        with self.spans.span("serve.readback", "serving"):
+            greedy = np.asarray(greedy)
+            n_acc_np = np.asarray(n_acc)
         reg = self.telemetry.registry if self.telemetry is not None else None
         for j, s in zip(rows, live_slots):
             pos_before = int(self.pos[s])
@@ -757,42 +813,22 @@ class ContinuousBatcher:
         else:
             self.h_last = h_new
 
-    def _emit_step(self, t0_us: float, n_tokens: int, n_done: int) -> None:
-        """One ``serve_step`` span + the backend occupancy/throughput
-        metrics, all read from state the step already maintains.
-        ``n_tokens`` is a delta of per-request token counts, so bucket
-        padding and idle decode rows can never inflate tokens/s — only
-        tokens appended to live (admitted, non-padded) requests count."""
-        tel = self.telemetry
-        tr = tel.tracer
-        dur_us = tr.now_us() - t0_us
-        cc = self.compile_cache
-        args = {"tokens": n_tokens, "retired": n_done,
-                "queued": self.n_queued,
-                "active": sum(r is not None for r in self.active),
-                "recompiles": cc.recompiles,
-                "kv_reserved_bytes": self.kv_reserved_bytes()}
-        reg = tel.registry
+    def _count_step(self, n_tokens: int) -> None:
+        """The step's registry metrics, all read from state the step
+        already maintains. ``n_tokens`` is a delta of per-request token
+        counts, so bucket padding and idle decode rows can never inflate
+        it — only tokens appended to live (admitted, non-padded) requests
+        count."""
+        reg = self.telemetry.registry
         if n_tokens:
             reg.counter("serving_tokens_total",
                         "tokens generated (prefill-sampled + decoded)").inc(
                 n_tokens)
-        if dur_us > 0:
-            reg.gauge("serving_tokens_per_s",
-                      "decode throughput of the last step").set(
-                n_tokens / (dur_us * 1e-6))
-        reg.gauge("serving_compile_cache_hit_rate",
-                  "compile-cache hit rate over all jit keys").set(
-            cc.hit_rate)
         rec = reg.counter("serving_recompiles_total",
                           "post-warmup compile-cache misses (bucket escapes)")
-        rec.inc(cc.recompiles - rec.value())
+        rec.inc(self.compile_cache.recompiles - rec.value())
         if self.backend == "paged":
             st = self.pm.stats
-            args.update(pages_in_use=st.pages_in_use,
-                        cow_copies=st.n_cow_copies - self._cow_mark,
-                        forks=st.n_forks - self._fork_mark)
-            self._cow_mark, self._fork_mark = st.n_cow_copies, st.n_forks
             reg.gauge("paged_pages_in_use",
                       "pages currently allocated").set(st.pages_in_use)
             reg.gauge("paged_pages_free", "pages currently free").set(
@@ -806,9 +842,6 @@ class ContinuousBatcher:
                 reg.gauge("paged_prefix_cached_pages",
                           "zero-ref pages parked in the prefix LRU").set(
                     self.pm.num_cached_pages)
-                reg.gauge("paged_prefix_cached_bytes",
-                          "KV bytes held by parked prefix pages").set(
-                    self.pm.cached_bytes())
                 reg.gauge("serving_prefix_hit_rate",
                           "cumulative prompt tokens served from cache").set(
                     self.prefix_hit_rate())
@@ -818,13 +851,6 @@ class ContinuousBatcher:
                 ev = reg.counter("paged_prefix_evictions_total",
                                  "parked pages evicted under pool pressure")
                 ev.inc(st.n_prefix_evictions - ev.value())
-                args.update(prefix_cached_pages=self.pm.num_cached_pages,
-                            prefix_hit_rate=round(self.prefix_hit_rate(), 4))
-            tr.sample("pages", {"in_use": st.pages_in_use,
-                                "free": self.pm.num_pages - st.pages_in_use},
-                      ts_us=t0_us + dur_us)
-        tr.complete(f"serve_step:{self.steps - 1}", "serving", t0_us, dur_us,
-                    **args)
 
     def step(self) -> List[Request]:
         """Admit, one decode step for all live slots, retire. Returns the
@@ -833,7 +859,10 @@ class ContinuousBatcher:
         is captured (owner table, top buffers, recent serve steps) before
         the re-raise."""
         try:
-            with shctx.use_mesh(self.mesh):
+            with shctx.use_mesh(self.mesh), self.spans.span(
+                    "serve.step", "serving", step=self.steps,
+                    queued=self.n_queued,
+                    live=sum(r is not None for r in self.active)):
                 done = self._step_inner()
         except Exception as e:
             fl = self.flight
@@ -857,11 +886,6 @@ class ContinuousBatcher:
         return done
 
     def _step_inner(self) -> List[Request]:
-        t0_us = None
-        if self.telemetry is not None:
-            t0_us = self.telemetry.tracer.now_us()
-            if not hasattr(self, "_cow_mark"):
-                self._cow_mark = self._fork_mark = 0
         tokens_before = self._tokens_outstanding() \
             if self.telemetry is not None else 0
         self._admit()
@@ -878,10 +902,9 @@ class ContinuousBatcher:
         self.steps += 1
         done = self._retire()
         if self.telemetry is not None:
-            n_tokens = (self._tokens_outstanding()
-                        + sum(len(r.out_tokens) for r in done)
-                        - tokens_before)
-            self._emit_step(t0_us, n_tokens, len(done))
+            self._count_step(self._tokens_outstanding()
+                             + sum(len(r.out_tokens) for r in done)
+                             - tokens_before)
         return done
 
     def _tokens_outstanding(self) -> int:

@@ -6,6 +6,7 @@ instrumentation, and the live_host_bytes / per_device_live_bytes("host")
 accounting."""
 import dataclasses
 import json
+import time
 
 import jax
 import jax.numpy as jnp
@@ -230,9 +231,132 @@ def test_serving_batcher_metrics():
     lat = reg.histogram("serving_admission_latency_s").summary()
     assert lat["count"] == 3
     assert reg.gauge("paged_pages_in_use").peak() > 0
-    steps = [s for s in tel.tracer.spans if s.cat == "serving"]
+    steps = [s for s in tel.tracer.spans if s.name == "serve.step"]
     assert len(steps) == cb.steps
-    assert all("kv_reserved_bytes" in s.args for s in steps)
+    assert [s.args["step"] for s in steps] == list(range(cb.steps))
+    assert all({"queued", "live"} <= s.args.keys() for s in steps)
+    assert all(s.cat == "serving" for s in tel.tracer.spans)
+    admits = [s for s in tel.tracer.spans if s.name == "serve.admit"]
+    assert sorted(s.args["rid"] for s in admits) == [0, 1, 2]
+
+
+def _profile_events(log_dir, prefix):
+    """Host events of the newest profile under ``log_dir`` whose names
+    start with ``prefix``: (name, start, end, stats, line), times in
+    wall-clock ns (the capture's start plus the event's offset)."""
+    import glob
+    import os
+    from jax.profiler import ProfileData
+    path = max(glob.glob(os.path.join(str(log_dir), "**", "*.xplane.pb"),
+                         recursive=True), key=os.path.getmtime)
+    pd = ProfileData.from_file(path)
+    t0 = next(dict(p.stats)["profile_start_time"] for p in pd.planes
+              if "profile_start_time" in dict(p.stats))
+    out = []
+    for plane in pd.planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for i, line in enumerate(plane.lines):
+            for e in line.events:
+                if e.name.startswith(prefix):
+                    out.append((e.name, t0 + e.start_ns, t0 + e.end_ns,
+                                dict(e.stats), (plane.name, i)))
+    return sorted(out, key=lambda ev: ev[1])
+
+
+def _paged_batcher(telemetry=None):
+    from repro.models import Model
+    from repro.serving import ContinuousBatcher
+    cfg = micro_cfg(num_kv_heads=2, head_dim=16, d_model=64)
+    model = Model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    # slots 4 over the ladder (2, 4): one to four live rows decode in 2-
+    # and 4-row programs; the 10-token prompt is past the prompt buckets
+    # (2, 8), so its prefill compiles after warm-up
+    cb = ContinuousBatcher(model, cfg, params, slots=4, capacity=32,
+                           temperature=0.0, seed=0, cache_backend="paged",
+                           page_size=8, capture_buckets=(2, 8),
+                           telemetry=telemetry)
+    rng = np.random.RandomState(0)
+    for plen, g in ((4, 3), (6, 7), (3, 5), (10, 2)):
+        cb.submit(rng.randint(0, cfg.vocab_size, size=plen), g)
+    return cb
+
+
+def test_serving_spans_reach_the_profile(tmp_path):
+    cb = _paged_batcher()
+    seen = []                                 # (live, kv_live) per decode
+    decode = cb._vanilla_decode
+
+    def spy(live_slots):
+        seen.append((len(live_slots),
+                     sum(int(cb.pos[s]) + 1 for s in live_slots)))
+        return decode(live_slots)
+
+    cb._vanilla_decode = spy
+    with jax.profiler.trace(str(tmp_path)):
+        done = cb.run_until_drained()
+    assert len(done) == 4
+    ev = _profile_events(tmp_path, "serve.")
+    steps = [e for e in ev if e[0] == "serve.step"]
+    assert len(steps) == cb.steps
+    assert [e[3]["step"] for e in steps] == list(range(cb.steps))
+    decodes = [e for e in ev if e[0] == "serve.decode"]
+    assert len(decodes) == len(seen) == cb.steps
+    for e in decodes + [e for e in ev if e[0] == "serve.readback"]:
+        assert any(s[4] == e[4] and s[1] <= e[1] and e[2] <= s[2]
+                   for s in steps), e[0]
+    for e, (live, kv_live) in zip(decodes, seen):
+        rows = 2 if live <= 2 else 4
+        assert e[3] == {"rows": rows, "live": live,
+                        "kv_gathered": rows * cb.max_blocks * cb.page_size,
+                        "kv_live": kv_live}
+    admits = [e for e in ev if e[0] == "serve.admit"]
+    assert [e[3]["rid"] for e in admits] == [0, 1, 2, 3]
+    assert [e[3]["bucket"] for e in admits] == [8, 8, 8, 10]
+    assert all(e[3]["wait_us"] >= 0 for e in admits)
+    assert sum(e[3]["done"] for e in ev if e[0] == "serve.retire") == 4
+    grow = [e for e in ev if e[0] == "serve.grow_pages"]
+    assert sum(e[3]["claimed"] for e in grow) > 0
+    (rec,) = [e for e in ev if e[0] == "serve.recompile"]
+    assert rec[3] == {"key": "prefill:paged:10"}
+    assert any(p[0] == "serve.prefill" and p[1] <= rec[1] and rec[2] <= p[2]
+               for p in ev)
+    for r in done:
+        assert r.t_submit <= r.t_admitted <= r.t_first_token
+
+
+def test_serving_without_telemetry_keeps_nothing():
+    from repro.obs import ProfilerSpans
+    plain = _paged_batcher()
+    assert type(plain.spans) is ProfilerSpans
+    toks = [r.out_tokens for r in sorted(plain.run_until_drained(),
+                                         key=lambda r: r.rid)]
+    # the recorder holds its clock and an empty stack, nothing else
+    assert set(vars(plain.spans)) == {"_t0", "_stack"}
+    assert plain.spans.depth == 0
+    tel = RunTelemetry.create(sim_delta=False)
+    kept = _paged_batcher(tel)
+    assert kept.spans is tel.tracer
+    assert toks == [r.out_tokens for r in sorted(kept.run_until_drained(),
+                                                 key=lambda r: r.rid)]
+    assert sum(s.name == "serve.step" for s in tel.tracer.spans) \
+        == kept.steps
+
+
+def test_span_export_and_profile_share_a_clock(tmp_path):
+    tr = SpanTracer()
+    with jax.profiler.trace(str(tmp_path)):
+        time.sleep(0.01)
+        with tr.span("serve.anchor", "serving", n=3):
+            time.sleep(0.005)
+    (name, start, end, stats, _), = _profile_events(tmp_path, "serve.")
+    assert stats == {"n": 3}
+    ev = next(e for e in tr.chrome_trace()["traceEvents"]
+              if e.get("name") == "serve.anchor")
+    anchor_ns = tr.chrome_trace()["otherData"]["t0_wall"] * 1e9
+    assert abs(anchor_ns + ev["ts"] * 1e3 - start) < 1e6
+    assert abs(ev["dur"] * 1e3 - (end - start)) < 1e6
 
 
 # ------------------------------------------------- host-bytes accounting
