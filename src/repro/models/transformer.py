@@ -627,9 +627,12 @@ class Model:
         return logits, new_pools
 
     def paged_decode_step(self, params, pools, token, position, block_tables,
-                          *, use_kernel: bool = False, adapter=None):
+                          *, use_kernel: Optional[bool] = None,
+                          adapter=None):
         """One-token decode over paged pools. token/position [B] (position
         is the logical index being written); block_tables [B, nb].
+        ``use_kernel`` forces the attention path (None: chosen by backend
+        and pool shape, ``paged.attention.decode_kernel_applies``).
         Returns (logits [B, V], pools)."""
         from repro.paged.attention import paged_attention_decode
         cfg = self.cfg

@@ -181,6 +181,7 @@ class ContinuousBatcher:
                 "spec decode is greedy-only (temperature=0, top_k=0)"
             self.h_last = jnp.zeros((slots, cfg.d_model), cache_dtype)
 
+        self._decode_kernel = False         # the paged decode kernel runs
         if cache_backend == "dense":
             self.caches = model.init_cache(slots, capacity, cache_dtype)
             self.caches = {"segments": place_kv_tp(self.caches, mesh),
@@ -232,6 +233,11 @@ class ContinuousBatcher:
             self.pools = place_kv_tp(
                 model.init_paged_pools(num_pages, page_size, cache_dtype),
                 mesh)
+            from repro.paged.attention import decode_kernel_applies
+            with shctx.use_mesh(mesh):
+                # the attention path the decode program traces with
+                self._decode_kernel = decode_kernel_applies(
+                    jax.tree.leaves(self.pools)[0].shape[1:], cache_dtype)
 
             def decode(params, pools, tok, pos, bt, key, live):
                 logits, pools = model.paged_decode_step(params, pools, tok,
@@ -698,19 +704,36 @@ class ContinuousBatcher:
             return self.slot_ladder.fit(n_live)
         return self.B
 
-    def _decode_span(self, live_slots: List[int], rows: int):
+    def _decode_span(self, live_slots: List[int], rows: int,
+                     kernel: bool = False):
         """The ``serve.decode`` span: rows computed and live, the KV
-        positions its attention gathers and those the live rows hold."""
+        positions the gather path's attention gathers, those the attention
+        reads (the paged decode kernel's, ``_kv_read``, where ``kernel``;
+        else the gathered ones) and those the live rows hold."""
         per_row = self.max_blocks * self.page_size \
             if self.backend == "paged" else self.capacity
+        gathered = rows * per_row
         return self.spans.span(
             "serve.decode", "serving", rows=rows, live=len(live_slots),
-            kv_gathered=rows * per_row,
+            kv_gathered=gathered,
+            kv_read=self._kv_read(live_slots) if kernel else gathered,
             kv_live=int(self.pos[live_slots].sum()) + len(live_slots))
+
+    def _kv_read(self, live_slots: List[int]) -> int:
+        """KV positions the paged decode kernel reads in a step: each lane
+        tile of ``GROUP`` pages that holds a page a live row attends to
+        (its blocks ``0 .. pos // page_size``), all of the tile."""
+        from repro.paged.attention import GROUP
+        bt = self.pm.block_table_array(
+            [self.active[s].rid for s in live_slots], self.max_blocks)
+        n = self.pos[live_slots] // self.page_size + 1
+        pages = bt[np.arange(self.max_blocks)[None, :] < n[:, None]]
+        tiles = np.unique(pages[pages >= 0] // GROUP)
+        return int(tiles.size) * GROUP * self.page_size
 
     def _vanilla_decode(self, live_slots: List[int]) -> None:
         nb = self._decode_rows(len(live_slots))
-        with self._decode_span(live_slots, nb):
+        with self._decode_span(live_slots, nb, self._decode_kernel):
             self.key, k = jax.random.split(self.key)
             if self.backend == "paged" and self.slot_ladder is not None:
                 # gather live rows into a slot bucket; pad rows are idle

@@ -264,7 +264,7 @@ def _profile_events(log_dir, prefix):
     return sorted(out, key=lambda ev: ev[1])
 
 
-def _paged_batcher(telemetry=None):
+def _paged_batcher(telemetry=None, num_pages=None):
     from repro.models import Model
     from repro.serving import ContinuousBatcher
     cfg = micro_cfg(num_kv_heads=2, head_dim=16, d_model=64)
@@ -276,7 +276,7 @@ def _paged_batcher(telemetry=None):
     cb = ContinuousBatcher(model, cfg, params, slots=4, capacity=32,
                            temperature=0.0, seed=0, cache_backend="paged",
                            page_size=8, capture_buckets=(2, 8),
-                           telemetry=telemetry)
+                           telemetry=telemetry, num_pages=num_pages)
     rng = np.random.RandomState(0)
     for plen, g in ((4, 3), (6, 7), (3, 5), (10, 2)):
         cb.submit(rng.randint(0, cfg.vocab_size, size=plen), g)
@@ -308,9 +308,10 @@ def test_serving_spans_reach_the_profile(tmp_path):
                    for s in steps), e[0]
     for e, (live, kv_live) in zip(decodes, seen):
         rows = 2 if live <= 2 else 4
-        assert e[3] == {"rows": rows, "live": live,
-                        "kv_gathered": rows * cb.max_blocks * cb.page_size,
-                        "kv_live": kv_live}
+        gathered = rows * cb.max_blocks * cb.page_size
+        # the gather path reads what it gathers
+        assert e[3] == {"rows": rows, "live": live, "kv_gathered": gathered,
+                        "kv_read": gathered, "kv_live": kv_live}
     admits = [e for e in ev if e[0] == "serve.admit"]
     assert [e[3]["rid"] for e in admits] == [0, 1, 2, 3]
     assert [e[3]["bucket"] for e in admits] == [8, 8, 8, 10]
@@ -324,6 +325,37 @@ def test_serving_spans_reach_the_profile(tmp_path):
                for p in ev)
     for r in done:
         assert r.t_submit <= r.t_admitted <= r.t_first_token
+
+
+def test_decode_span_counts_the_kernels_reads(tmp_path, monkeypatch):
+    """Where the paged decode kernel runs (forced here, in interpret mode),
+    ``serve.decode``'s ``kv_read`` is every position of each 128-page tile
+    that holds a page a live row attends to."""
+    import repro.paged.attention as A
+    monkeypatch.setattr(A, "decode_kernel_applies", lambda *a: True)
+    cb = _paged_batcher(num_pages=256)
+    assert cb._decode_kernel
+    # hand pages out from both tiles in turn
+    cb.pm._free = [p for pair in zip(range(128), range(128, 256))
+                   for p in pair][::-1]
+    seen = []
+    decode = cb._vanilla_decode
+
+    def spy(live_slots):
+        tiles = set()
+        for s in live_slots:
+            bt = cb.pm.block_table_array([cb.active[s].rid], cb.max_blocks)
+            tiles |= {int(p) // 128
+                      for p in bt[0, :cb.pos[s] // cb.page_size + 1]}
+        seen.append(len(tiles) * 128 * cb.page_size)
+        return decode(live_slots)
+
+    cb._vanilla_decode = spy
+    with jax.profiler.trace(str(tmp_path)):
+        assert len(cb.run_until_drained()) == 4
+    decodes = [e for e in _profile_events(tmp_path, "serve.decode")]
+    assert [e[3]["kv_read"] for e in decodes] == seen
+    assert max(seen) == 2 * 128 * cb.page_size
 
 
 def test_serving_without_telemetry_keeps_nothing():

@@ -14,6 +14,7 @@ from repro.models import Model
 from repro.paged import (PageManager, PagePoolExhausted, append_decode,
                          paged_attention_reference, paged_decode_attention,
                          scatter_prefill)
+from repro.paged.attention import paged_append
 from repro.rlhf import Rollout
 from repro.serving import ContinuousBatcher
 
@@ -172,6 +173,110 @@ def test_paged_attention_matches_dense(B, H, K, D, ps, nb, dt):
                                  interpret=True)
     np.testing.assert_allclose(np.asarray(ker, np.float32),
                                np.asarray(ref, np.float32), atol=tol)
+
+
+def _paged_case(rng, P, ps, nb, K, D, positions, pages_of, dt):
+    """A random pool and block tables: row b holds ``pages_of(b, n)`` for
+    the ``n`` blocks its position needs (-1 past them)."""
+    pool = {"k": jnp.asarray(rng.randn(P, ps, K, D), dt),
+            "v": jnp.asarray(rng.randn(P, ps, K, D), dt)}
+    bt = np.full((len(positions), nb), -1, np.int32)
+    for b, pos in enumerate(positions):
+        if pos >= 0:
+            n = pos // ps + 1
+            bt[b, :n] = pages_of(b, n)
+    return pool, jnp.asarray(bt), jnp.asarray(positions, jnp.int32)
+
+
+# (H, K, D, ps, nb, P, positions, where the pages come from)
+KERNEL_CASES = {
+    # 25 x 64 in small: an odd head count at head dim 64
+    "mha-odd-heads": (5, 5, 64, 4, 6, 128, [9, 22, 3, 15], "ordered"),
+    "gqa": (8, 2, 64, 4, 6, 128, [7, 20, 11], "ordered"),
+    # a pad row, a row whose last page holds one token, a row inside one
+    # page (every row here is shorter than one 128-page block)
+    "ragged": (4, 4, 32, 4, 6, 128, [-1, 8, 2, 23, -1], "ordered"),
+    # tables out of order over the whole pool, -1 tails
+    "shuffled-tables": (4, 2, 64, 4, 8, 256, [13, 30, 5, 26], "shuffled"),
+    # pages in the first and third tile only: the middle one is skipped
+    "dead-tile": (4, 4, 64, 4, 6, 384, [17, 22, 6], "tiles-0-2"),
+}
+
+
+@pytest.mark.parametrize("case", list(KERNEL_CASES))
+def test_paged_decode_kernel_matches_reference(case):
+    H, K, D, ps, nb, P, positions, order = KERNEL_CASES[case]
+    rng = np.random.RandomState(sum(map(ord, case)))
+    pages = {"ordered": np.arange(P), "shuffled": rng.permutation(P),
+             "tiles-0-2": np.concatenate(
+                 [rng.permutation(128), 256 + rng.permutation(128)])[
+                     rng.permutation(256)]}[order]
+    taken = iter(pages)
+    pool, bt, pos = _paged_case(
+        rng, P, ps, nb, K, D, positions,
+        lambda b, n: [next(taken) for _ in range(n)], jnp.bfloat16)
+    q = jnp.asarray(rng.randn(len(positions), H, D), jnp.bfloat16)
+    ref = np.asarray(paged_attention_reference(q, pool, bt, pos), np.float32)
+    ker = np.asarray(paged_decode_attention(q, pool["k"], pool["v"], bt, pos,
+                                            interpret=True), np.float32)
+    live = np.asarray(pos) >= 0
+    np.testing.assert_allclose(ker[live], ref[live], atol=2e-2)
+    assert (ker[~live] == 0).all()          # pad rows read nothing
+
+
+@pytest.mark.parametrize("P,positions", [
+    # two rows at slot 1 (one slab when their pages share a tile), a pad
+    # row, a row whose position is past its blocks (no page: dropped)
+    (256, [1, 5, 6, 1, -1, 9]),
+    (12, [0, 3, 4, 7, 2, -1]),          # fewer pages than a tile
+])
+def test_paged_append_matches_append_decode(P, positions):
+    ps, K, D, nb = 4, 5, 64, 2
+    rng = np.random.RandomState(P)
+    B = len(positions)
+    pool = {n: jnp.asarray(rng.randn(P, ps, K, D), jnp.bfloat16)
+            for n in ("k", "v")}
+    bt = jnp.asarray(rng.permutation(P)[:B * nb].reshape(B, nb), jnp.int32)
+    if P > 128:                          # rows 0 and 3 in one slab
+        bt = bt.at[3, 0].set(bt[0, 0] ^ 1)
+    pos = jnp.asarray(positions, jnp.int32)
+    kt, vt = (jnp.asarray(rng.randn(B, K, D), jnp.bfloat16) for _ in "kv")
+    want = append_decode(pool, kt, vt, bt, pos)
+    got = paged_append(pool["k"], pool["v"], kt, vt, bt, pos,
+                       interpret=True)
+    for g, n in zip(got, ("k", "v")):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(want[n]))
+
+
+def test_model_paged_decode_kernel_path_matches_reference_path():
+    """``Model.paged_decode_step`` through the kernel (interpret mode) gives
+    the gather path's logits and greedy tokens, a pad row included."""
+    cfg = _tiny_cfg()
+    model = Model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    B, P_len, ps = 3, 8, 4
+    toks = jnp.asarray(np.random.RandomState(0).randint(0, 64, (B, P_len)))
+    pm = PageManager(128, ps)
+    for b in range(2):
+        pm.allocate(b, P_len + 6)
+    bt = jnp.asarray(pm.block_table_array([0, 1, None], 4))
+    lens = jnp.asarray([P_len, P_len - 3, 0], jnp.int32)
+    pools0 = model.init_paged_pools(128, ps, jnp.float32)
+    _, pools0 = model.paged_prefill(params, {"tokens": toks}, pools0, bt,
+                                    lens)
+    pools = {True: pools0, False: pools0}
+    tok = jnp.asarray([3, 5, 0], jnp.int32)
+    pos = jnp.asarray([P_len, P_len - 3, -1], jnp.int32)
+    for _ in range(4):
+        lg = {}
+        for kernel in (True, False):
+            lg[kernel], pools[kernel] = model.paged_decode_step(
+                params, pools[kernel], tok, pos, bt, use_kernel=kernel)
+        np.testing.assert_allclose(np.asarray(lg[True])[:2],
+                                   np.asarray(lg[False])[:2], atol=1e-4)
+        tok = jnp.argmax(lg[False], -1).astype(jnp.int32)
+        assert (jnp.argmax(lg[True], -1)[:2] == tok[:2]).all()
+        pos = jnp.where(pos >= 0, pos + 1, -1)
 
 
 def test_append_decode_writes_only_live_rows():

@@ -80,14 +80,58 @@ def test_decode_attention_compiles(on_chip):
                     on_chip((B, C), jnp.int32), on_chip((B,), jnp.int32))
 
 
-def test_paged_decode_attention_compiles(on_chip):
-    from repro.paged.attention import paged_decode_attention
-    B, pages, page, blocks = 8, 144, 16, 18
-    _compile_kernel(paged_decode_attention, on_chip((B, H, D)),
-                    on_chip((pages, page, H, D)),
-                    on_chip((pages, page, H, D)),
-                    on_chip((B, blocks), jnp.int32),
-                    on_chip((B,), jnp.int32))
+def _pool_copies(text, shape):
+    """The compiled program's copies and transposes of a ``[P, ps, K, D]``
+    pool, or of its ``[ps, K, D, P]`` view."""
+    P, ps, K, D = shape
+    shapes = (f"[{P},{ps},{K},{D}]", f"[{ps},{K},{D},{P}]")
+    return [line for line in text.splitlines()
+            if any(s in line for s in shapes)
+            and (" copy(" in line or " transpose(" in line)]
+
+
+@pytest.mark.parametrize("B,pages,page,K,hd,blocks,in_place", [
+    (32, 1024, 16, 25, D, 64, True),     # serve-chat: GPT2-xl, 25 x 64
+    (8, 256, 16, H, D, 18, True),        # an OPT-1.3b rollout
+    (8, 256, 16, 8, 128, 32, False),     # 8 KV heads of 128: row-major
+])
+def test_paged_decode_attention_compiles(on_chip, B, pages, page, K, hd,
+                                         blocks, in_place):
+    """The paged decode kernel reads the pool as the TPU keeps it: no
+    transpose or copy of the pool comes before the kernel, for each pool
+    its shape rule admits. A pool of whole lane tiles stays row-major, the
+    kernel's view would copy it, and the rule keeps it off."""
+    from repro.paged.attention import decode_kernel_applies, \
+        kernel_reads_in_place, paged_decode_attention
+    pool = on_chip((pages, page, K, hd))
+    assert kernel_reads_in_place(pool.shape, pool.dtype) == in_place
+    compiled = _compile_kernel(paged_decode_attention, on_chip((B, K, hd)),
+                               pool, pool, on_chip((B, blocks), jnp.int32),
+                               on_chip((B,), jnp.int32))
+    pool_ops = _pool_copies(compiled.as_text(), pool.shape)
+    assert bool(pool_ops) != in_place, pool_ops
+    assert not decode_kernel_applies(pool.shape, pool.dtype)   # CPU here
+
+
+def test_paged_append_compiles(on_chip):
+    """The kernel path's token write is in place on the pool as the TPU
+    keeps it (serve-chat's shapes): no copy or transpose of the pool."""
+    from repro.paged.attention import paged_append
+    B, pages, page, K, blocks = 32, 1024, 16, 25, 64
+    pool = on_chip((pages, page, K, D))
+    new = on_chip((B, K, D))
+    compiled = jax.jit(functools.partial(paged_append, interpret=False),
+                       donate_argnums=(0, 1)).lower(
+        pool, pool, new, new, on_chip((B, blocks), jnp.int32),
+        on_chip((B,), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert not _pool_copies(compiled.as_text(), pool.shape)
+
+
+def test_paged_decode_kernel_rule_needs_whole_page_tiles():
+    from repro.paged.attention import kernel_reads_in_place
+    assert kernel_reads_in_place((256, 16, H, D), jnp.bfloat16)
+    assert not kernel_reads_in_place((144, 16, H, D), jnp.bfloat16)
 
 
 def test_rmsnorm_compiles(on_chip):
